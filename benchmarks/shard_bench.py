@@ -1,6 +1,12 @@
-"""Mesh-serving benchmark: 1-device engine vs an 8-fake-CPU-device
-(2x4 data x model) mesh — tok/s plus **per-device HBM-resident param +
-KV-pool bytes** (``BENCH_shard.json``, written by ``benchmarks/run.py``).
+"""Mesh-serving rehearsal on the CPU: 1-device engine vs an 8-fake-CPU-device
+(2x4 data x model) mesh — tok/s plus **per-device param + KV-pool bytes**
+(``BENCH_shard.json``, written by ``benchmarks/run.py``).
+
+This is a CPU-only rehearsal, on every machine: its child process is
+forced onto the CPU (``JAX_PLATFORMS=cpu``) and never touches a chip, and
+every row and the report carry the platform the child measured
+(``platform``).  Its tok/s is host-CPU time, never a device metric; the
+four-chip mesh is measured by ``chip_smoke.py --four-chips``.
 
 The point on a CPU host is the MEMORY column, not the speed column: the
 8 fake devices share one physical CPU, so the sharded engine pays real
@@ -102,6 +108,7 @@ _SCRIPT = textwrap.dedent(
             "pool_bytes_per_device": bytes_on_dev0(pool),
         }
         del pool
+    rows["platform"] = jax.devices()[0].platform
     print("RESULT " + json.dumps(rows))
     """
 )
@@ -123,11 +130,13 @@ def run() -> list[tuple[str, float, str]]:
         raise RuntimeError(f"shard_bench subprocess failed:\n{proc.stderr[-3000:]}")
     line = next(l for l in proc.stdout.splitlines() if l.startswith("RESULT "))
     rows = json.loads(line[len("RESULT "):])
+    platform = rows.pop("platform")
 
     one, sharded = rows["1x1"], rows["2x4"]
     param_cut = one["params_bytes_per_device"] / sharded["params_bytes_per_device"]
     pool_cut = one["pool_bytes_per_device"] / sharded["pool_bytes_per_device"]
     rep = {
+        "platform": platform,
         "workload": {"arch": "kanformer-100m (reduced)", "paged": True,
                      "smoke": _smoke()},
         "meshes": rows,
@@ -141,10 +150,10 @@ def run() -> list[tuple[str, float, str]]:
     run.last_report = rep  # type: ignore[attr-defined]
     return [
         ("shard.1x1", one["wall_s"] * 1e6,
-         f"tok/s={one['tokens_per_s']:.1f} "
+         f"platform={platform} tok/s={one['tokens_per_s']:.1f} "
          f"param_B/dev={one['params_bytes_per_device']}"),
         ("shard.2x4", sharded["wall_s"] * 1e6,
-         f"tok/s={sharded['tokens_per_s']:.1f} "
+         f"platform={platform} tok/s={sharded['tokens_per_s']:.1f} "
          f"param_B/dev={sharded['params_bytes_per_device']}"),
         ("shard.cut", 0.0,
          f"param_bytes/dev x{param_cut:.2f}, pool_bytes/dev x{pool_cut:.2f}, "

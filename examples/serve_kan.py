@@ -88,8 +88,7 @@ def main(argv=None):
             for _ in range(12)
         ]
     print(f"backend={jax.default_backend()} engine={args.engine} "
-          f"kan_method_prefill={resolve_inference_method(rows=4 * 24)} "
-          f"kan_method_decode={resolve_inference_method(rows=4)} "
+          f"kan_method={resolve_inference_method()} "
           f"decode=scan (one compiled program per generation/chunk)")
     t0 = time.time()
     if args.engine == "continuous":

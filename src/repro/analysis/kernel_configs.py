@@ -8,13 +8,19 @@ container (interpret mode executes anything); it fails on the first real
 TPU run, long after the PR merged.  This validator makes those configs
 fail **lint** instead:
 
-* **KL201 VMEM budget** — per-grid-step tile footprint (double-buffered
-  input/output blocks + the fp32 scratch accumulator) must fit the ~16 MiB
-  core VMEM, and the contraction width ``bk·unit`` must respect the shared
-  ``_MAX_CONTRACT`` budget (DESIGN.md §2/§2a).
+* **KL201 VMEM budget** — the per-grid-step footprint screened by
+  ``autotune.tile_vmem_bytes`` must fit the 16 MiB scoped VMEM, and the
+  contraction width ``bk·unit`` must respect the shared ``_MAX_CONTRACT``
+  budget (DESIGN.md §2/§2a).  The footprint model counts the pipelined
+  blocks, the scratch accumulator and the basis temporaries; it does not
+  see Mosaic's own spills and relayouts, so it only screens.  The
+  authority on what compiles is ``tests/test_tpu_compile.py``, which
+  AOT-compiles the served kernels for a v5e chip.
 * **KL202 dtype tiling alignment** (TPU only) — batch tiles ``bb`` must be
-  sublane-aligned for the dtype (fp32 8, bf16 16, int8 32) and output
-  tiles ``bn`` lane-aligned (128).
+  sublane-aligned for the dtype (fp32 8, bf16 16, int8 32), and ``bn``
+  and ``bk`` (the lane dims of the output and ``x`` blocks) must be
+  multiples of 128 or the whole ``N`` / ``K``.  Only kernels compiled for
+  the TPU (``autotune.TPU_KERNELS``) are checked for it.
 * **KL203 grid fit** — tiles must not exceed the minimally padded problem
   dims (an oversized tile means a grid that never covers its block).
 
@@ -34,7 +40,6 @@ import jax.numpy as jnp
 from repro.analysis.findings import Finding
 from repro.kernels import autotune as tune
 
-VMEM_BYTES = 16 * 2**20       # per-core VMEM (Pallas guide)
 LANE = 128                     # last-dim tiling granularity on TPU
 
 # Representative problems (BS, K, N): serving prefill, decode, and a small
@@ -57,31 +62,6 @@ def _line_of(marker: str) -> int:
     except OSError:
         pass
     return 1
-
-
-def tile_vmem_bytes(
-    kernel: str, tiles: tune.Tiles, M: int, dtype, *,
-    has_base: bool = True, out_bytes: int | None = None,
-) -> int:
-    """Per-grid-step VMEM footprint of one (bb, bn, bk) tiling.
-
-    Blocks per step: x ``(bb, bk)``, coeff ``(bk·M, bn)`` dense-band or
-    ``(bk, M, bn)`` sparse (same element count), optional base ``(bk, bn)``,
-    out ``(bb, bn)``, fp32 scratch accumulator ``(bb, bn)`` (the sparse and
-    int8 kernels carry a second index/int32 scratch of the same shape).
-    Input/output blocks are double-buffered (×2) for the async copy
-    pipeline; scratch is not.
-    """
-    bb, bn, bk = tiles
-    e = jnp.dtype(dtype).itemsize
-    oe = out_bytes if out_bytes is not None else e
-    blocks = bb * bk * e + bk * M * bn * e + bb * bn * oe
-    if has_base:
-        blocks += bk * bn * e
-    scratch = bb * bn * 4
-    if tune.is_sparse_kernel(kernel) or "int8" in kernel:
-        scratch += bb * bn * 4
-    return 2 * blocks + scratch
 
 
 def validate_tiles(
@@ -111,14 +91,14 @@ def validate_tiles(
             "shrink bk or widen the budget deliberately in autotune.py",
         ))
     if backend == "tpu":
-        vmem = tile_vmem_bytes(kernel, tiles, M, dtype,
-                               has_base=has_base, out_bytes=out_bytes)
-        if vmem > VMEM_BYTES:
+        vmem = tune.tile_vmem_bytes(kernel, tiles, M, dtype,
+                                    has_base=has_base, out_bytes=out_bytes)
+        if vmem > tune.VMEM_BYTES:
             out.append(Finding(
                 "KL201", path, line,
                 f"{what}: tile VMEM footprint {vmem} B exceeds the "
-                f"{VMEM_BYTES} B core budget",
-                "shrink bb/bn/bk until double-buffered blocks + scratch fit",
+                f"{tune.VMEM_BYTES} B scoped budget",
+                "shrink bb/bn/bk until blocks, scratch and temporaries fit",
             ))
         sub = tune._SUBLANE.get(jnp.dtype(dtype).name, 8)
         if bb % sub:
@@ -128,12 +108,14 @@ def validate_tiles(
                 f"sublane granularity {sub}",
                 f"round bb up to a multiple of {sub}",
             ))
-        if bn % LANE:
-            out.append(Finding(
-                "KL202", path, line,
-                f"{what}: bn={bn} violates the {LANE}-lane granularity",
-                f"round bn up to a multiple of {LANE}",
-            ))
+        for name, t, whole in (("bn", bn, N), ("bk", bk, K)):
+            if t % LANE and t != whole:
+                out.append(Finding(
+                    "KL202", path, line,
+                    f"{what}: {name}={t} is a lane dim but neither a multiple "
+                    f"of {LANE} nor the whole dim ({whole})",
+                    f"use a multiple of {LANE} or the whole dim",
+                ))
     sub = tune._SUBLANE.get(jnp.dtype(dtype).name, 8)
     lane = LANE if backend == "tpu" else 8
     if bb > tune._round_up(BS, sub) or bn > tune._round_up(N, lane) or bk > K:
@@ -158,8 +140,9 @@ def validate_candidate_spaces() -> list[Finding]:
     line = _line_of("def candidate_tiles")
     out: list[Finding] = []
     for kernel, spec in _registry().items():
+        backends = ("tpu", "cpu") if kernel in tune.TPU_KERNELS else ("cpu",)
         for dtype in spec["dtypes"]:
-            for backend in ("tpu", "cpu"):
+            for backend in backends:
                 for BS, K, N in PROBLEM_SUITE:
                     cands = tune.candidate_tiles(
                         kernel, BS, K, N, spec["M"], dtype, backend,
@@ -207,10 +190,10 @@ def validate_defaults() -> list[Finding]:
 
 
 def validate_measurement_cache() -> list[Finding]:
-    """Every entry of the measurement cache currently in force
-    (``$KAN_SAS_AUTOTUNE_CACHE`` / the default path): a hand-edited or
-    stale winner reaches ``ops.py`` with zero compile-time checks, so it
-    gets the same static validation as the in-repo tables."""
+    """Every entry of the measurement cache ``$KAN_SAS_AUTOTUNE_CACHE``
+    names (none when unset): a hand-edited or stale winner reaches
+    ``ops.py`` with zero compile-time checks, so it gets the same static
+    validation as the in-repo tables."""
     cache = tune._load_cache()
     if not cache:
         return []

@@ -43,15 +43,32 @@ def abstract_signature(args: tuple, kwargs: dict) -> str:
     return repr(tree)
 
 
+def _abstract_arg(x):
+    """A traced argument as a ``ShapeDtypeStruct`` (weak type kept); static
+    python values pass through."""
+    aval = getattr(x, "aval", None)
+    if aval is None:
+        return x
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype, weak_type=aval.weak_type)
+
+
 class RetraceRegistry:
     """Per-name trace counters.  One registry per Engine."""
 
     def __init__(self) -> None:
         self._traces: dict[str, dict[str, int]] = {}
+        self._calls: dict[str, dict[str, tuple]] = {}
 
-    def record(self, name: str, signature: str) -> None:
+    def record(self, name: str, signature: str, call: tuple | None = None) -> None:
         sigs = self._traces.setdefault(name, {})
         sigs[signature] = sigs.get(signature, 0) + 1
+        if call is not None:
+            self._calls.setdefault(name, {})[signature] = call
+
+    def calls(self, name: str) -> list[tuple]:
+        """``(args, kwargs)`` per program traced under ``name``, arrays as
+        ``ShapeDtypeStruct`` — enough to ``lower`` the program again."""
+        return list(self._calls.get(name, {}).values())
 
     def programs(self, name: str) -> int:
         """Distinct abstract signatures traced under ``name`` — the number
@@ -85,7 +102,10 @@ def counting(fn, name: str, registry: RetraceRegistry):
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
-        registry.record(name, abstract_signature(args, kwargs))
+        registry.record(
+            name, abstract_signature(args, kwargs),
+            jax.tree_util.tree_map(_abstract_arg, (args, kwargs)),
+        )
         return fn(*args, **kwargs)
 
     return wrapped

@@ -24,17 +24,16 @@ Forward paths (selectable, all numerically cross-checked in tests):
 * ``sparse``  — Pallas kernel: the paper's N:M vector PE (§IV-A/B). Each input
   contracts only its ``P+1`` non-zero values against a *gathered*
   ``(P+1, N)`` coefficient slab — ``(G+P)/(P+1)×`` fewer MACs and
-  coefficient reads than ``fused``; wins in the memory-bound small-batch /
-  decode regime (DESIGN.md §2a).
-* ``auto``    — :func:`resolve_inference_method`: on TPU, ``sparse`` at decode
-  row counts and ``fused`` otherwise; ``compact`` off-TPU (interpret-mode
-  Pallas is correct but slow on CPU).
+  coefficient reads than ``fused`` (DESIGN.md §2a).
+* ``auto``    — :func:`resolve_inference_method`: ``fused`` on TPU,
+  ``compact`` off-TPU (interpret-mode Pallas is correct but slow on CPU).
+  ``sparse`` runs in interpret mode only (its slab gather does not lower
+  to Mosaic).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any
 
@@ -119,62 +118,30 @@ def kan_layer_lut(
     return y + _base_term(params, x)
 
 
-@functools.lru_cache(maxsize=4)
-def _sparse_kernel_compiles(backend: str) -> bool:
-    """Probe (once per process) that the deployed compiler can lower the
-    sparse kernel's VMEM gather (Mosaic dynamic-gather) — so ``auto`` can
-    fall back to the proven fused kernel instead of failing every decode
-    step on a jaxlib without it.  Only probes when the queried backend is
-    the *actual* one (hypothetical queries, e.g. a CPU-hosted dry-run asking
-    about TPU, assume support)."""
-    if backend != "tpu" or jax.default_backend() != "tpu":
-        return True  # off-TPU runs interpret mode: plain XLA gather
-    try:
-        from repro.kernels import ops as kops
+def resolve_inference_method(backend: str | None = None) -> str:
+    """The serving path per backend (DESIGN.md §2a).
 
-        g = SplineGrid()
-        x = jnp.zeros((1, 2), jnp.float32)
-        c = jnp.zeros((2, g.n_basis, 8), jnp.float32)
-        jax.block_until_ready(
-            kops.kan_sparse_gemm(x, c, g, bb=8, bn=8, bk=2, interpret=False)
-        )
-        return True
-    except Exception:
-        return False
+    On TPU: the ``fused`` kernel at every row count (one kernel per layer,
+    B never in HBM — DESIGN.md §2).  The ``sparse`` N:M kernel is not on
+    the TPU path: its per-input coefficient-slab gather is a 4-D gather
+    Mosaic does not lower, and the gather-free rendering of it is the
+    fused kernel's dense band.  Off-TPU: ``compact`` (interpret-mode
+    Pallas is correct on CPU but orders of magnitude slower than the XLA
+    gather path).
 
-
-def resolve_inference_method(
-    backend: str | None = None, rows: int | None = None
-) -> str:
-    """The default serving path per backend and batch regime (DESIGN.md §2a).
-
-    On TPU: the ``sparse`` N:M kernel when the flattened row count is in the
-    decode/small-batch regime (``rows <= $KAN_SAS_SPARSE_MAX_ROWS``,
-    default 8) — there the dense-band GEMM is memory-bound and the sparse
-    kernel's ``(G+P)/(P+1)×`` smaller coefficient stream wins; the ``fused``
-    kernel otherwise (one kernel per layer, B never in HBM — DESIGN.md §2).
-    Off-TPU: ``compact`` (interpret-mode Pallas is correct on CPU but orders
-    of magnitude slower than the XLA gather path).
-
-    ``rows`` is the number of flattened input rows the layer will see
-    (batch·seq for prefill, batch for decode); when unknown (``None``) the
-    large-batch answer is returned.  ``$KAN_SAS_INFERENCE_METHOD`` overrides
-    everything — e.g. a CPU-hosted dry-run lowering the program it will
-    actually serve on TPU sets it to ``fused``, and a TPU debug session can
-    force ``compact``.
+    ``$KAN_SAS_INFERENCE_METHOD`` names a method to use instead, e.g.
+    ``fused`` to run the TPU kernel in interpret mode on a CPU; naming
+    ``sparse`` on a TPU is an error.
     """
     import os
 
-    forced = os.environ.get("KAN_SAS_INFERENCE_METHOD")
-    if forced:
-        return forced
     backend = backend or jax.default_backend()
-    if backend != "tpu":
-        return "compact"
-    max_rows = int(os.environ.get("KAN_SAS_SPARSE_MAX_ROWS", "8"))
-    if rows is not None and rows <= max_rows and _sparse_kernel_compiles(backend):
-        return "sparse"
-    return "fused"
+    method = os.environ.get("KAN_SAS_INFERENCE_METHOD") or (
+        "fused" if backend == "tpu" else "compact"
+    )
+    if backend == "tpu" and method == "sparse":
+        raise ValueError("the sparse kernel is not compiled for the TPU")
+    return method
 
 
 def kan_layer_apply(
@@ -185,9 +152,7 @@ def kan_layer_apply(
     lut: jax.Array | None = None,
 ) -> jax.Array:
     if method == "auto":
-        # rows = flattened inputs the kernel will see: the batch-regime
-        # signal that picks sparse (decode) vs fused (prefill/train) on TPU.
-        method = resolve_inference_method(rows=math.prod(x.shape[:-1]))
+        method = resolve_inference_method()
     if method == "dense":
         return kan_layer_dense(params, x, grid)
     if method == "compact":

@@ -219,6 +219,17 @@ class ShardingCtx:
         """Sharding for a leading-batch host array (tokens, positions)."""
         return NamedSharding(self.mesh, batch_spec(self.mesh, batch))
 
+    def row_axis(self, n: int):
+        """The mesh axes ``n`` rows split over (None when nothing divides)."""
+        return batch_spec(self.mesh, n)[0]
+
+    def kernel_map(self, fn, in_specs, out_specs):
+        """``fn`` run per device under ``shard_map``.  Pallas (Mosaic)
+        kernels are opaque to GSPMD, which refuses to partition them, so
+        the model wraps every kernel call on a multi-device mesh in one."""
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
+
     # -- constraints (used inside jitted model code) --------------------
 
     def constrain(self, x, names: tuple):

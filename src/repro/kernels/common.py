@@ -1,15 +1,18 @@
 """In-kernel building blocks shared by the KAN Pallas kernels.
 
-Both the floating-point (`kan_fused_gemm`) and integer (`kan_int8_gemm`)
-datapaths need the same two pieces of the paper's architecture rendered as
-branch-free vector code:
+The floating-point (`kan_fused_gemm`), integer (`kan_int8_gemm`) and
+sparse (`kan_sparse_gemm`) datapaths share these pieces of the paper's
+architecture rendered as branch-free vector code:
 
 * the B-spline unit (§III-A): evaluate the ``P+1`` non-zero cardinal
   B-spline values for a tile of inputs entirely in VMEM/registers
-  (:func:`compact_basis_inblock`, :func:`cardinal_values_inblock`);
-* the M-to-N multiplexer run in reverse (§IV-B): place those compact values
-  into the dense ``M = G+P`` band of an MXU tile with compare-selects — no
-  gathers, no scatters (:func:`band_scatter`);
+  (:func:`cardinal_planes`, one Cox-de Boor for every kernel);
+* the M-to-N multiplexer run in reverse (§IV-B): place those values into
+  the dense ``M = G+P`` band with compare-selects — no gathers, no
+  scatters.  :func:`band_planes` keeps the basis index unrolled (one
+  ``(bb, bk)`` plane per basis function, the layout the TPU fused kernel
+  compiles with); :func:`band_scatter` lays it out on a trailing axis
+  (the int8 kernel, interpret mode);
 * the M-to-N multiplexer run *forward* (§IV-B, the N:M vector PE of the
   sparse kernels): gather, per input, the ``(P+1, N)`` coefficient slab its
   non-zero basis values touch (:func:`gather_coeff_slabs`);
@@ -17,54 +20,86 @@ branch-free vector code:
   datapath (Eq. 5), shared by the dense-band and sparse int8 kernels
   (:func:`int8_compact_values_inblock`).
 
-Everything here lowers inside a TPU kernel with iota / where / arithmetic,
-except :func:`gather_coeff_slabs`, which is a VMEM gather (plain XLA ops in
-interpret mode; requires Mosaic dynamic-gather support when compiled).
+Only the fused kernel's path (:func:`band_planes`) is compiled for the TPU
+(``tests/test_tpu_compile.py``).  The trailing-axis helpers pad their
+``P+1`` / ``M`` minor axis to 128 lanes under Mosaic, and
+:func:`gather_coeff_slabs` is a 4-D gather Mosaic does not lower; the
+kernels built on them run in interpret mode.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bspline import SplineGrid
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+
+def cardinal_planes(xa: jax.Array, P: int) -> list[jax.Array]:
+    """Cardinal B-spline values ``B_{0,P}(xa + (P - i))`` for ``i = 0..P``.
+
+    ``xa`` is the in-interval offset in ``[0, 1)``; returns ``P+1`` arrays
+    of ``xa.shape`` ordered by ascending basis index.  Runs the Cox-de Boor
+    triangle as a list of planes, one per knot segment: since
+    ``u_i = xa + (P-i)`` lies in ``[P-i, P-i+1)``, the degree-0 coefficient
+    vector for point ``i`` is the indicator ``e_{P-i}``.  Keeping the basis
+    axis as a Python list (never a trailing array axis) lets a TPU kernel
+    keep ``xa``'s own lane layout for every temporary.
+    """
+    out = []
+    for i in range(P + 1):
+        u = xa + float(P - i)
+        b = [
+            jnp.where((u >= float(s)) & (u < float(s + 1)), 1.0, 0.0).astype(xa.dtype)
+            for s in range(P + 1)
+        ]
+        for p in range(1, P + 1):
+            b = [
+                (u - float(s)) / float(p) * b[s]
+                + (float(s + p + 1) - u) / float(p) * b[s + 1]
+                for s in range(P + 1 - p)
+            ]
+        out.append(b[0])
+    return out
 
 
 def cardinal_values_inblock(xa: jax.Array, P: int) -> jax.Array:
-    """Cardinal B-spline values ``B_{0,P}(xa + (P - i))`` for ``i = 0..P``.
+    """:func:`cardinal_planes` stacked on a trailing ``(P+1,)`` axis."""
+    return jnp.stack(cardinal_planes(xa, P), axis=-1)
 
-    ``xa`` is the in-interval offset in ``[0, 1)``; the result has shape
-    ``xa.shape + (P+1,)`` ordered by ascending basis index.  Runs the
-    Cox-de Boor triangle on a ``(P+2)``-wide band: since
-    ``u_i = xa + (P-i)`` lies in ``[P-i, P-i+1)``, the degree-0 coefficient
-    vector for point ``i`` is the indicator ``e_{P-i}``.
+
+def _align_inblock(x: jax.Array, grid: SplineGrid) -> tuple[jax.Array, jax.Array]:
+    """Align + Compare units: in-interval offset ``xa`` (f32) and interval
+    index ``k``, identical math to :func:`repro.core.bspline.compact_basis`.
+    Evaluation runs in float32 regardless of ``x.dtype`` (the Cox-de Boor
+    triangle loses too much in bf16)."""
+    P = grid.P
+    xf = x.astype(jnp.float32)
+    z = (xf - jnp.float32(grid.t0)) / jnp.float32(grid.delta)
+    k = jnp.clip(jnp.floor(z).astype(jnp.int32), P, grid.n_basis - 1)
+    xa = jnp.clip(z - k.astype(jnp.float32), 0.0, 1.0)
+    return xa, k
+
+
+def band_planes(x: jax.Array, grid: SplineGrid) -> list[jax.Array]:
+    """All ``M = G+P`` dense basis planes ``B_m(x)``, each of ``x.shape``
+    (float32) — the B-spline unit and the M-to-N multiplexer in reverse,
+    with the basis index unrolled instead of laid out on an array axis.
+
+    Plane ``m`` holds ``vals[i]`` where ``m == k - P + i`` and zero
+    elsewhere: compare-selects, no gathers, and every temporary keeps
+    ``x``'s ``(rows, lanes)`` layout.
     """
-    dtype = xa.dtype
-    offs = dtype.type(P) - jax.lax.broadcasted_iota(
-        jnp.int32, xa.shape + (P + 1,), xa.ndim
-    ).astype(dtype)
-    u = xa[..., None] + offs                                    # (..., P+1)
-    nseg = P + 2
-    seg = jax.lax.broadcasted_iota(jnp.int32, u.shape + (nseg - 1,), u.ndim)
-    b = jnp.where(
-        (u[..., None] >= seg.astype(dtype)) & (u[..., None] < (seg + 1).astype(dtype)),
-        dtype.type(1.0),
-        dtype.type(0.0),
-    )                                                           # (..., P+1, P+1)
-    for p in range(1, P + 1):
-        idx = jax.lax.broadcasted_iota(
-            jnp.int32, u.shape + (nseg - 1 - p,), u.ndim
-        ).astype(dtype)
-        left = (u[..., None] - idx) / dtype.type(p) * b[..., :-1]
-        right = (idx + dtype.type(p + 1) - u[..., None]) / dtype.type(p) * b[..., 1:]
-        b = left + right
-    return b[..., 0]
+    xa, k = _align_inblock(x, grid)
+    vals = cardinal_planes(xa, grid.P)
+    base = k - grid.P
+    planes = []
+    for m in range(grid.n_basis):
+        plane = jnp.zeros(x.shape, jnp.float32)
+        for i, v in enumerate(vals):
+            plane = plane + jnp.where(base == m - i, v, 0.0)
+        planes.append(plane)
+    return planes
 
 
 def compact_basis_inblock(
@@ -74,17 +109,11 @@ def compact_basis_inblock(
 
     Returns ``vals: x.shape + (P+1,)`` (ascending basis index) and the
     interval index ``k``.  Identical math to
-    :func:`repro.core.bspline.compact_basis`; written to lower cleanly
-    inside a TPU kernel.  Evaluation runs in float32 regardless of
-    ``x.dtype`` (the Cox-de Boor triangle loses too much in bf16); callers
-    cast the resulting band to the MXU input dtype.
+    :func:`repro.core.bspline.compact_basis`; callers cast the values to
+    the contraction dtype.
     """
-    P = grid.P
-    xf = x.astype(jnp.float32)
-    z = (xf - jnp.float32(grid.t0)) / jnp.float32(grid.delta)
-    k = jnp.clip(jnp.floor(z).astype(jnp.int32), P, grid.n_basis - 1)
-    xa = jnp.clip(z - k.astype(jnp.float32), 0.0, 1.0)
-    return cardinal_values_inblock(xa, P), k
+    xa, k = _align_inblock(x, grid)
+    return cardinal_values_inblock(xa, grid.P), k
 
 
 def band_scatter(vals: jax.Array, k: jax.Array, M: int) -> jax.Array:

@@ -10,11 +10,11 @@ Computes the **whole** KAN layer of Eq. 1 in one kernel:
 This is the TPU rendering of the paper's two architectural moves:
 
 * the B-spline unit "directly streams its values into the systolic array"
-  (§III-A): here, each grid step evaluates the compact ``P+1`` non-zero
-  values *in VMEM/registers* from the raw ``x`` tile;
+  (§III-A): here, each grid step evaluates the basis *in VMEM/registers*
+  from the raw ``x`` tile;
 * the N:M vector PE with its M-to-N multiplexer (§IV-B): the multiplexer
   becomes a branch-free compare-select that places the compact values into
-  the dense band of an MXU tile (:func:`repro.kernels.common.band_scatter`).
+  the dense band (:func:`repro.kernels.common.band_planes`).
 
 The base term ``w_b · ReLU(x)`` of Eq. 1 rides along as an **epilogue
 contraction on the same x tile**: the tile is already resident in VMEM for
@@ -22,6 +22,15 @@ the spline evaluation, so the base GEMM costs zero extra HBM reads of ``x``
 and no second kernel launch.  HBM traffic drops from ``X + B + C + Y``
 (dense-B baseline, plus another ``X + Wb + Y`` for a separate base GEMM) to
 ``X + C + Wb + Y`` — see DESIGN.md §2 for the roofline accounting.
+
+TPU layout.  The contraction dim ``bk`` sits on the lanes of the ``x``
+tile, so it is a multiple of 128 or the whole ``K``.  The basis is built as
+``M`` unrolled ``(bb, bk)`` planes — never a trailing ``(..., M)`` axis,
+which Mosaic would pad to 128 lanes (16x the VMEM for ``M = 8``) and then
+relayout to flatten.  The coefficient block is ``(bk, M, bn)`` straight
+from the ``(K, M, N)`` parameter (no HBM transpose); plane ``m`` contracts
+with its ``[:, m, :]`` slice, so the layer is ``M + 1`` MXU matmuls of
+``(bb, bk) @ (bk, bn)`` per grid step.
 
 Accumulation is float32 in a VMEM scratch tile regardless of the input
 dtype (bf16 inputs hit the MXU in bf16 but never round the partial sums);
@@ -42,11 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bspline import SplineGrid
-from repro.kernels.common import (
-    CompilerParams,
-    band_scatter,
-    compact_basis_inblock,
-)
+from repro.kernels.common import band_planes
 
 
 def _fused_kernel(*refs, grid: SplineGrid, has_base: bool):
@@ -55,27 +60,23 @@ def _fused_kernel(*refs, grid: SplineGrid, has_base: bool):
     else:
         x_ref, c_ref, y_ref, acc_ref = refs
         bw_ref = None
-    M = grid.n_basis
     x = x_ref[...]                                    # (bb, bk)
-    vals, k = compact_basis_inblock(x, grid)          # f32 (bb, bk, P+1), i32
-
-    # M-to-N multiplexer, run in reverse (paper §IV-B): place the compact
-    # values into the dense band with compare-selects — no gathers.
-    band = band_scatter(vals, k, M)                   # f32 (bb, bk, M)
-
-    bb, bk = x.shape
-    c = c_ref[...]                                    # (bk*M, bn)
-    B_tile = band.reshape(bb, bk * M).astype(c.dtype)  # VMEM only, never HBM
-    acc = jnp.dot(B_tile, c, preferred_element_type=jnp.float32)
-
+    acc = None
     if has_base:
         # Base-term epilogue (Eq. 1): the x tile is already in VMEM — one
         # extra MXU contraction, zero extra HBM traffic for x.
         xb = jnp.maximum(x, jnp.zeros((), x.dtype))   # ReLU in input dtype
-        acc = acc + jnp.dot(
+        acc = jnp.dot(
             xb.astype(bw_ref.dtype), bw_ref[...],
             preferred_element_type=jnp.float32,
         )
+    # B tile: M planes in VMEM only, never HBM; plane m meets C[:, m, :].
+    for m, plane in enumerate(band_planes(x, grid)):
+        part = jnp.dot(
+            plane.astype(c_ref.dtype), c_ref[:, m, :],
+            preferred_element_type=jnp.float32,
+        )
+        acc = part if acc is None else acc + part
 
     kk = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -103,7 +104,7 @@ def kan_fused_gemm_pallas(
     base_w: jax.Array | None = None,
     bb: int = 128,
     bn: int = 128,
-    bk: int = 16,
+    bk: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused KAN layer. ``x: (BS, K)``, ``coeff: (K, M, N)``,
@@ -111,8 +112,10 @@ def kan_fused_gemm_pallas(
 
     When ``base_w`` is given the base term ``ReLU(x) @ base_w`` is fused
     into the kernel epilogue — spline + base in a single ``pallas_call``.
-    Block sizes default to MXU-friendly tiles (contraction width ``bk*M``);
-    inputs are padded to block multiples (padded features carry zero
+    Compiled for TPU, ``bk``/``bn`` are multiples of 128 or the whole
+    ``K``/``N`` and ``bb`` a multiple of the dtype's sublane count (the
+    autotuner's TPU tiles obey this); interpret mode takes any tiling.
+    Inputs are padded to block multiples (padded features carry zero
     coefficients/base weights, hence contribute nothing).
     """
     BS, K = x.shape
@@ -122,14 +125,13 @@ def kan_fused_gemm_pallas(
     pb, pk, pn = -BS % bb, -K % bk, -N % bn
     xp = jnp.pad(x, ((0, pb), (0, pk)), constant_values=grid.x_min)
     cp = jnp.pad(coeff, ((0, pk), (0, 0), (0, pn)))
-    c2 = cp.reshape((K + pk) * M, N + pn)
     gb, gn, gk = (BS + pb) // bb, (N + pn) // bn, (K + pk) // bk
 
     in_specs = [
         pl.BlockSpec((bb, bk), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((bk * M, bn), lambda i, j, kk: (kk, j)),
+        pl.BlockSpec((bk, M, bn), lambda i, j, kk: (kk, 0, j)),
     ]
-    operands = [xp, c2]
+    operands = [xp, cp]
     if has_base:
         assert base_w.shape == (K, N), (base_w.shape, (K, N))
         bwp = jnp.pad(base_w.astype(coeff.dtype), ((0, pk), (0, pn)))
@@ -143,7 +145,7 @@ def kan_fused_gemm_pallas(
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((BS + pb, N + pn), x.dtype),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -36,7 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bspline import SplineGrid
 from repro.kernels.common import (
-    CompilerParams,
     band_scatter,
     int8_compact_values_inblock,
 )
@@ -155,7 +154,7 @@ def kan_int8_gemm_pallas(
             (BS + pb, N + pn), out_dtype if has_scale else jnp.int32
         ),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -43,7 +43,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bspline import SplineGrid
 from repro.kernels.common import (
-    CompilerParams,
     compact_basis_inblock,
     gather_coeff_slabs,
     int8_compact_values_inblock,
@@ -156,7 +155,7 @@ def kan_sparse_gemm_pallas(
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((BS + pb, N + pn), x.dtype),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -263,7 +262,7 @@ def kan_sparse_int8_gemm_pallas(
             (BS + pb, N + pn), out_dtype if has_scale else jnp.int32
         ),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -55,15 +55,6 @@ DTYPE_BYTES = {
 }
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize Compiled.cost_analysis() across jax versions (list of
-    per-program dicts on some, a plain dict on others)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def collective_bytes(hlo_text: str) -> dict:
     """Sum result bytes per collective class from optimized HLO.
 
@@ -281,7 +272,7 @@ def run_cell(
         "temp_bytes": int(ma.temp_size_in_bytes),
         "peak_bytes": int(ma.peak_memory_in_bytes),
     }
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     result["cost"] = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
@@ -344,7 +335,7 @@ def _lower_costfaithful(model, cell, mesh, arch_name, n_rep):
                 out_shardings=(None, cache_shard),
             ).lower(params_in, inputs["tokens"], caches_in, inputs["pos"])
         compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
@@ -425,6 +416,8 @@ def main() -> None:
     os.makedirs(ART_DIR, exist_ok=True)
 
     if args.all:
+        # The parent only lists cells and spawns children: it builds no
+        # mesh and touches no device, so each child owns its own backend.
         jobs = []
         archs = list(configs.ASSIGNED) + (
             ["kanformer-100m"] if args.include_kanformer else []

@@ -1,11 +1,28 @@
-"""Production mesh construction (brief: MULTI-POD DRY-RUN step 1).
+"""Mesh construction.
 
-A FUNCTION, not a module-level constant — importing this module never
-touches jax device state."""
+FUNCTIONS, not module-level constants — importing this module never
+touches jax device state.  Every mesh in the repo (launchers, serving,
+tests) comes from :func:`auto_mesh`, whose axes are ``AxisType.Auto``:
+``jax.make_mesh`` without ``axis_types`` gives ``Explicit`` axes, under
+which ``with_sharding_constraint`` (``dist/sharding.py: ShardingCtx``) and
+GSPMD-propagated reshapes refuse to run."""
 
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None) -> Mesh:
+    """A mesh with ``Auto`` axes over ``devices`` (default: this process's
+    devices via ``jax.make_mesh``).  Pass ``devices`` to build a mesh from a
+    described topology (``jax.experimental.topologies``) for AOT compiles."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    devs = np.asarray(devices)[: int(np.prod(shape))].reshape(shape)
+    return Mesh(devs, tuple(axes), axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape: tuple[int, int] | None = None):
@@ -31,7 +48,7 @@ def make_host_mesh(shape: tuple[int, int] | None = None):
     must divide the device count (no stranded remainder)."""
     n = len(jax.devices())
     if shape is None:
-        return jax.make_mesh((1, n), ("data", "model"))
+        return auto_mesh((1, n), ("data", "model"))
     d, m = int(shape[0]), int(shape[1])
     if d < 1 or m < 1:
         raise ValueError(f"mesh shape must be positive, got {(d, m)}")
@@ -41,7 +58,7 @@ def make_host_mesh(shape: tuple[int, int] | None = None):
             f"{n} ({'too few' if d * m > n else 'not divisible'}); force "
             f"more with XLA_FLAGS=--xla_force_host_platform_device_count=N"
         )
-    return jax.make_mesh((d, m), ("data", "model"))
+    return auto_mesh((d, m), ("data", "model"))
 
 
 def parse_mesh_shape(s: str) -> tuple[int, int]:

@@ -16,6 +16,11 @@ optionally ``--draft-quant``) proposes K tokens per window and one fused
 verify pass scores them — outputs stay bit-identical to ``--spec-k 0``
 (DESIGN.md §9).  Reduced (CPU-runnable) shapes are the default; ``--full``
 selects the full production config.
+
+The launcher needs a TPU; it runs on the CPU (Pallas kernels in interpret
+mode) only when ``JAX_PLATFORMS=cpu`` asks for it.  Compiled programs are
+kept in JAX's persistent cache (``launch/runtime.py``).  ``chip_smoke.py``
+drives the same :func:`build_engine` / :func:`serve` path.
 """
 
 from __future__ import annotations
@@ -29,8 +34,15 @@ import numpy as np
 
 from repro import configs
 from repro.launch.mesh import make_host_mesh, parse_mesh_shape
+from repro.launch.runtime import enable_compile_cache, require_tpu
 from repro.models import lm
 from repro.serve.engine import Engine, ServeConfig
+
+
+# Activations and KV cache are served in bf16 (params stay fp32), the dtype
+# the TPU kernels are compiled and checked for (tests/test_tpu_compile.py,
+# chip_smoke.py).
+SERVED_DTYPE = "bfloat16"
 
 
 def pick_config(arch: str, full: bool):
@@ -83,6 +95,40 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def build_engine(args, model) -> Engine:
+    """Params from ``--seed`` and the engine the flags describe.  Raises
+    ``ValueError`` for a ``--mesh`` the host cannot hold."""
+    params = lm.init_params(jax.random.PRNGKey(args.seed), model)
+    max_seq = args.prompt_len + args.max_new + 8
+    if args.paged:   # the paged pool addresses whole blocks
+        max_seq = -(-max_seq // args.block_size) * args.block_size
+    mesh = None
+    if args.mesh is not None:
+        mesh = make_host_mesh(parse_mesh_shape(args.mesh))
+        print(f"[serve] mesh={dict(mesh.shape)} over {mesh.size} "
+              f"of {len(jax.devices())} host devices")
+    return Engine(
+        params, model,
+        ServeConfig(max_seq=max_seq,
+                    max_new_tokens=args.max_new, temperature=args.temperature,
+                    eos_id=args.eos_id, compute_dtype=SERVED_DTYPE,
+                    paged=args.paged, block_size=args.block_size,
+                    pool_blocks=args.pool_blocks, mesh=mesh,
+                    spec_k=args.spec_k, draft_layers=args.draft_layers,
+                    draft_quant=args.draft_quant),
+    )
+
+
+def serve(eng: Engine, args, requests: list[np.ndarray]) -> list[np.ndarray]:
+    """Run ``requests`` through the engine ``--engine`` names."""
+    if args.engine == "continuous":
+        return eng.serve_continuous(
+            requests, slots=args.batch, chunk_steps=args.chunk_steps,
+            seed=args.seed,
+        )
+    return eng.serve_requests(requests, batch_size=args.batch, seed=args.seed)
+
+
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
 
@@ -110,42 +156,20 @@ def main(argv=None) -> int:
               f"(needs token-input full-attention GQA blocks)",
               file=sys.stderr)
         return 2
-    params = lm.init_params(jax.random.PRNGKey(args.seed), model)
-    max_seq = args.prompt_len + args.max_new + 8
-    if args.paged:   # the paged pool addresses whole blocks
-        max_seq = -(-max_seq // args.block_size) * args.block_size
-    mesh = None
-    if args.mesh is not None:
-        try:
-            mesh = make_host_mesh(parse_mesh_shape(args.mesh))
-        except ValueError as e:
-            print(f"[serve] {e}", file=sys.stderr)
-            return 2
-        print(f"[serve] mesh={dict(mesh.shape)} over {mesh.size} "
-              f"of {len(jax.devices())} host devices")
-    eng = Engine(
-        params, model,
-        ServeConfig(max_seq=max_seq,
-                    max_new_tokens=args.max_new, temperature=args.temperature,
-                    eos_id=args.eos_id, paged=args.paged,
-                    block_size=args.block_size, pool_blocks=args.pool_blocks,
-                    mesh=mesh, spec_k=args.spec_k,
-                    draft_layers=args.draft_layers,
-                    draft_quant=args.draft_quant),
-    )
+    enable_compile_cache()
+    require_tpu(allow_explicit_cpu=True)
+    try:
+        eng = build_engine(args, model)
+    except ValueError as e:
+        print(f"[serve] {e}", file=sys.stderr)
+        return 2
     rs = np.random.RandomState(args.seed)
     reqs = [
         rs.randint(0, model.vocab, rs.randint(4, args.prompt_len + 1)).astype(np.int32)
         for _ in range(args.requests)
     ]
     t0 = time.time()
-    if args.engine == "continuous":
-        outs = eng.serve_continuous(
-            reqs, slots=args.batch, chunk_steps=args.chunk_steps,
-            seed=args.seed,
-        )
-    else:
-        outs = eng.serve_requests(reqs, batch_size=args.batch, seed=args.seed)
+    outs = serve(eng, args, reqs)
     dt = time.time() - t0
     total_new = sum(len(o) for o in outs)
     print(f"[serve:{args.engine}] {len(reqs)} requests, {total_new} tokens "

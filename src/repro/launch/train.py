@@ -25,6 +25,7 @@ from repro.checkpoint import store
 from repro.data import pipeline as dp
 from repro.dist import sharding as SH
 from repro.launch.mesh import make_host_mesh
+from repro.launch.runtime import enable_compile_cache, require_tpu
 from repro.models import lm
 from repro.optim import adamw
 from repro.train import step as train_step_lib
@@ -48,6 +49,8 @@ def main(argv=None) -> int:
     ap.add_argument("--max-retries", type=int, default=2)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    require_tpu(allow_explicit_cpu=True)
     arch = configs.get_reduced(args.arch) if args.reduced else configs.get_config(args.arch)
     model = arch.model
     mesh = make_host_mesh()

@@ -15,6 +15,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
 from repro.models.layers import ParamCtx
@@ -466,13 +467,45 @@ def _cache_attend(
 # ---------------------------------------------------------------------------
 
 
-def paged_view(cache: dict, table: jax.Array) -> dict:
+def gather_pool(
+    pool: jax.Array, table: jax.Array, names: tuple, shard=None,
+    stacked: bool = False,
+) -> jax.Array:
+    """One pool leaf's logical view ``(B, L·bs, ...)`` through the
+    block-table gather kernel (``stacked``: a leading layers axis, gathered
+    per layer).  ``names`` are the leaf's :data:`POOL_CACHE_AXES`.
+
+    On a multi-device mesh the kernel runs per device under
+    ``shard.kernel_map``: table rows split like the view's batch axis, the
+    pool's trailing axes keep their sharding (``kv_heads`` on ``model``),
+    and every device sees all blocks (a block axis sharded over the data
+    axes is gathered first)."""
+    from repro.kernels.paged_gather import gather_blocks
+
+    def gather(p, t):
+        if stacked:
+            return jax.vmap(lambda q: gather_blocks(q, t))(p)
+        return gather_blocks(p, t)
+
+    if shard is None or shard.n_devices == 1:
+        return gather(pool, table)
+    lead = (None,) if stacked else ()
+    rest = tuple(shard.spec(names, pool.shape[len(lead):]))[1:]
+    r = shard.row_axis(table.shape[0])
+    return shard.kernel_map(
+        gather, in_specs=(P(*lead, None, *rest), P(r, None)),
+        out_specs=P(*lead, r, *rest),
+    )(pool, table)
+
+
+def paged_view(cache: dict, table: jax.Array, shard=None) -> dict:
     """Materialise the logical contiguous view of a paged cache tree:
     pools ``(n_blocks, bs, ...)`` + table ``(B, L)`` -> ``(B, L·bs, ...)``
     leaves, gathered with the block-table kernel."""
-    from repro.kernels.paged_gather import gather_blocks
-
-    return {name: gather_blocks(pool, table) for name, pool in cache.items()}
+    return {
+        name: gather_pool(pool, table, POOL_CACHE_AXES[name], shard)
+        for name, pool in cache.items()
+    }
 
 
 def paged_route(
@@ -564,7 +597,7 @@ def attn_decode_step_paged(
     cache = _paged_write_token(cache, "k", k, table, pos_b, cfg.kv_quant)
     cache = _paged_write_token(cache, "v", v, table, pos_b, cfg.kv_quant)
     cache = _constrain_cache(cache, shard, paged=True)
-    y = _cache_attend(params, cfg, x, paged_view(cache, table), q, pos_b)
+    y = _cache_attend(params, cfg, x, paged_view(cache, table, shard), q, pos_b)
     return y, cache
 
 
@@ -612,7 +645,7 @@ def attn_prefill_paged(
     # flash sweep from max_seq to ~the padded prompt length — the same
     # work the dense prefill does.
     view = paged_view(cache, table if view_blocks is None
-                      else table[:, :view_blocks])
+                      else table[:, :view_blocks], shard)
     ck = _read_cache(view, "k", cfg.kv_quant, x.dtype)
     cv = _read_cache(view, "v", cfg.kv_quant, x.dtype)
     ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), start, axis=1)
@@ -626,7 +659,7 @@ def attn_prefill_paged(
 # Speculative verify windows (DESIGN.md §9): score W = k+1 draft positions in
 # ONE batch-shaped pass against the live decode cache.  The whole point of
 # speculative decoding here is shape conversion — k sequential decode steps
-# (rows = B, the sparse/memory-bound regime) become one pass with B·W rows
+# (rows = B, the memory-bound regime) become one pass with B·W rows
 # (the fused-kernel regime) — so these entry points must NOT be a scan of
 # decode steps.  Bit-identity with the sequential path instead rests on the
 # same per-element-reduction argument the batch dimension already relies on:
@@ -784,7 +817,7 @@ def attn_verify_window_paged(
     cache = paged_write_span(cache, "k", k, table, pos_b, pos_b + W, cfg.kv_quant)
     cache = paged_write_span(cache, "v", v, table, pos_b, pos_b + W, cfg.kv_quant)
     cache = _constrain_cache(cache, shard, paged=True)
-    y = _cache_attend_window(params, cfg, x, paged_view(cache, table), q, pos_b)
+    y = _cache_attend_window(params, cfg, x, paged_view(cache, table, shard), q, pos_b)
     return y, cache
 
 

@@ -16,6 +16,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import kan_layer as KL
 from repro.core.bspline import SplineGrid
@@ -67,26 +68,58 @@ def _kan_ffn_init(ctx: ParamCtx, d: int, ff: int, grid: SplineGrid) -> dict:
 
 
 def _kan_ffn(
-    params: dict, x: jax.Array, grid: SplineGrid, method: str = "dense"
+    params: dict, x: jax.Array, grid: SplineGrid, method: str = "dense",
+    shard=None,
 ) -> jax.Array:
     """Two spline layers d -> ff -> d.
 
     ``method="dense"`` is the differentiable training path; inference
     callers (prefill/decode) pass ``method="auto"``, which resolves per
-    backend AND batch regime (``KL.resolve_inference_method``): on TPU the
-    sparse N:M kernel at decode row counts, the fused kernel for
-    prefill/large batch; ``compact`` elsewhere.
+    backend (``KL.resolve_inference_method``): the fused kernel on TPU,
+    ``compact`` elsewhere.  On a multi-device mesh (``shard``) the FFN runs
+    per device (:func:`_kan_ffn_per_device`).
     """
     lead = x.shape[:-1]
     xf = jnp.tanh(x.reshape(-1, x.shape[-1]))   # squash into the spline domain
+    if shard is not None and shard.n_devices > 1:
+        y = _kan_ffn_per_device(params, xf, grid, method, shard)
+    else:
+        y = _kan_ffn_rows(params, xf, grid, method)
+    return y.reshape(lead + (y.shape[-1],)).astype(x.dtype)
+
+
+def _kan_ffn_rows(params: dict, xf: jax.Array, grid: SplineGrid,
+                  method: str) -> jax.Array:
     h = KL.kan_layer_apply(
         {"coeff": params["c1"], "base_w": params["b1"]}, xf, grid, method
     )
     h = jnp.tanh(h)
-    y = KL.kan_layer_apply(
+    return KL.kan_layer_apply(
         {"coeff": params["c2"], "base_w": params["b2"]}, h, grid, method
     )
-    return y.reshape(lead + (y.shape[-1],)).astype(x.dtype)
+
+
+def _kan_ffn_per_device(params: dict, xf: jax.Array, grid: SplineGrid,
+                        method: str, shard) -> jax.Array:
+    """The FFN under ``shard.kernel_map``, Megatron style: the Pallas
+    kernels are opaque to GSPMD, which refuses to partition them.  ``c1``/
+    ``b1`` split on their ffn columns and ``c2``/``b2`` on their ffn rows,
+    as the parameter shardings already place them; one psum over that mesh
+    axis joins the partial outputs.  Rows split over the data axes."""
+    m = shard.spec(("ffn",), params["c1"].shape[-1:])[0]
+    r = shard.row_axis(xf.shape[0])
+
+    def body(x, c1, b1, c2, b2):
+        y = _kan_ffn_rows({"c1": c1, "b1": b1, "c2": c2, "b2": b2}, x, grid,
+                          method)
+        return y if m is None else jax.lax.psum(y, m)
+
+    return shard.kernel_map(
+        body,
+        in_specs=(P(r, None), P(None, None, m), P(None, m), P(m, None, None),
+                  P(m, None)),
+        out_specs=P(r, None),
+    )(xf, params["c1"], params["b1"], params["c2"], params["b2"])
 
 
 def block_init(ctx: ParamCtx, d_model: int, blk: BlockCfg) -> dict:
@@ -271,9 +304,8 @@ def block_prefill(
             y2, _ = M.moe_forward(params["moe"], blk.moe, h2)
             x = x + y2
         else:
-            # inference path, batch-regime aware: fused Pallas kernel on TPU
-            # at prefill row counts, sparse at decode, compact elsewhere
-            x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, method="auto")
+            # inference path: fused Pallas kernel on TPU, compact elsewhere
+            x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, "auto", shard)
         if shard is not None:
             cache = shard.constrain_tree(cache, block_cache_axes(blk))
         return x, cache
@@ -325,7 +357,7 @@ def block_prefill_paged(
     else:
         # same batch-regime-aware inference path as block_prefill — row
         # counts differ (suffix only), but every KAN method is row-wise
-        x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, method="auto")
+        x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, "auto", shard)
     return x, cache
 
 
@@ -422,9 +454,8 @@ def block_decode_step(
             y2, _ = M.moe_forward(params["moe"], blk.moe, h2)
             x = x + y2
         else:
-            # inference path, batch-regime aware: decode sees B·1 rows, so
-            # "auto" resolves to the sparse N:M kernel on TPU
-            x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, method="auto")
+            # inference path: fused Pallas kernel on TPU, compact elsewhere
+            x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, "auto", shard)
         return x, cache
     if blk.kind == "mamba2":
         y, cache = S.mamba2_decode_step(params["mamba"], blk.mamba, h, cache)
@@ -477,5 +508,5 @@ def block_verify_window(
         y2, _ = M.moe_forward(params["moe"], blk.moe, h2)
         x = x + y2
     else:
-        x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, method="auto")
+        x = x + _kan_ffn(params["kan"], h2, blk.kan_grid, "auto", shard)
     return x, cache

@@ -363,13 +363,8 @@ def paged_views(caches: dict, table: jax.Array, shard=None) -> dict:
     footprint; the pool remains the only persistent KV store).  With
     ``shard`` the gathered view is constraint-pinned to the dense cache
     shardings (batch on ``data``, kv_heads on ``model``)."""
-    from repro.kernels.paged_gather import gather_blocks
-
     def leaf(key, pool, stacked):
-        if stacked:
-            v = jax.vmap(lambda p: gather_blocks(p, table))(pool)
-        else:
-            v = gather_blocks(pool, table)
+        v = A.gather_pool(pool, table, A.POOL_CACHE_AXES[key], shard, stacked)
         if shard is not None:
             v = shard.constrain(v, _leaf_names(A.DENSE_CACHE_AXES, key, stacked))
         return v

@@ -292,6 +292,27 @@ class Engine:
             donate_argnums=(4,),
         )
 
+        # retrace-sentinel name -> jitted program, for lowered()
+        self._programs = {
+            "prefill": self._prefill, "decode": self._decode,
+            "decode_chunk": self._decode_scan,
+            "prefill_insert": self._prefill_insert,
+            "prefill_pages": self._prefill_pages,
+            "keys_first": self._keys_first,
+            "gather_views": self._gather_views,
+            "writeback_chunk": self._writeback_chunk,
+            "draft_chunk": self._draft_chunk, "verify_window": self._verify,
+            "draft_prefill": self._draft_prefill,
+        }
+
+    def lowered(self, name: str) -> list:
+        """Every program compiled so far under retrace-sentinel ``name``,
+        lowered again from the abstract arguments it was traced with
+        (``.compile().as_text()`` shows what ran, e.g. ``tpu_custom_call``
+        for the Pallas kernels)."""
+        prog = self._programs[name]
+        return [prog.lower(*a, **kw) for a, kw in self.compiles.calls(name)]
+
     # ------------------------------------------------------------------
     # cache construction: on a mesh the trees are built under jit with
     # explicit out_shardings (dist/sharding.py derives them from the

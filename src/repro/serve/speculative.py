@@ -1,8 +1,7 @@
 """Speculative decoding: a cheap KAN drafter + fused batch verification.
 
 Decode is the regime where the paper's kernels are starved: one token per
-step means ``rows = B`` — the memory-bound sparse-path regime (DESIGN.md
-§2a).  Speculative decoding converts ``k`` sequential target decode steps
+step means ``rows = B`` — the memory-bound regime (DESIGN.md §2a).  Speculative decoding converts ``k`` sequential target decode steps
 into (a) ``k`` steps of a much cheaper *drafter* and (b) ONE verification
 pass scoring all ``W = k + 1`` window positions — batch-shaped work
 (``rows = B·W``) that resolves to the fused KAN kernel on TPU

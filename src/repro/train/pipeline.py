@@ -26,23 +26,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.5 exposes shard_map at the top level (axis_names/check_vma)
-    from jax import shard_map
-except ImportError:  # 0.4.x: experimental module; partial-auto (auto=...)
-    # trips an XLA partitioner limitation, so fall back to FULL-manual over
-    # all mesh axes.  Equivalent here: the PP body only names "pod" and its
-    # other operands are replicated over data/model — each (data, model)
-    # replica just redundantly computes the same (correct) loss.
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None, **_kw):
-        return _shard_map_04(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=bool(check_vma),
-        )
 
 from repro.models import blocks as B
 from repro.models import layers as L

@@ -214,6 +214,20 @@ def test_kl202_alignment_tpu_only():
     assert "KL202" not in rules_of(fs_cpu)
 
 
+def test_kl202_bk_is_a_lane_dim():
+    """``bk`` is the lane dim of the x block: a multiple of 128 or all of K
+    (bk=16 at K=512 is what Mosaic refused for the fused kernel)."""
+    from repro.analysis import kernel_configs as kc
+
+    fs = kc.validate_tiles("fused", (16, 256, 16), 16, 512, 1024, 8,
+                           "bfloat16", "tpu", None, origin="unit test")
+    assert "KL202" in rules_of(fs)
+    for tiles, K in (((16, 256, 256), 512), ((16, 128, 64), 64)):
+        fs = kc.validate_tiles("fused", tiles, 16, K, 1024, 8,
+                               "bfloat16", "tpu", None, origin="unit test")
+        assert "KL202" not in rules_of(fs), fs
+
+
 def test_kl203_oversized_tile():
     from repro.analysis import kernel_configs as kc
 

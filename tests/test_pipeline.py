@@ -12,10 +12,11 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import configs
+    from repro.launch.mesh import auto_mesh
     from repro.models import lm
     from repro.train import pipeline as PP
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     arch = configs.get_reduced("qwen1.5-0.5b")
     model = arch.model   # 2 repeats -> 2 stages x 1
     params = lm.init_params(jax.random.PRNGKey(0), model)

@@ -15,6 +15,7 @@ from repro.dist.sharding import (
     spec_for,
     zero_spec,
 )
+from repro.launch.mesh import auto_mesh
 from repro.models.layers import Axes
 
 
@@ -155,7 +156,7 @@ def test_make_host_mesh_default_and_shapes():
 
 
 def test_sharding_ctx_single_device_degrades_to_replication():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     ctx = ShardingCtx(mesh)
     assert ctx.n_devices == 1
     # size-1 mesh axes divide everything, so specs still NAME them — but

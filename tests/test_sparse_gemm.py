@@ -4,7 +4,7 @@
   tolerance on ragged (non-tile-multiple) shapes, fp32 and bf16, with and
   without the base term — one ``pallas_call`` per layer;
 * the sparse int8 kernel is bit-identical to the dense-band int8 kernel;
-* ``resolve_inference_method`` picks sparse at decode row counts on TPU;
+* ``resolve_inference_method`` never picks sparse on TPU;
 * the autotuner knows the sparse kernels (per-kernel candidate spaces) and
   the cache survives corruption, mutation, and concurrent writers.
 """
@@ -143,22 +143,24 @@ class TestSparseInt8:
 
 class TestMethodResolution:
     def test_sparse_at_decode_rows_on_tpu(self):
-        assert kl.resolve_inference_method("tpu", rows=1) == "sparse"
-        assert kl.resolve_inference_method("tpu", rows=8) == "sparse"
-        assert kl.resolve_inference_method("tpu", rows=9) == "fused"
+        """The sparse kernel does not lower to Mosaic, so decode rows on a
+        TPU take the fused kernel like every other row count."""
         assert kl.resolve_inference_method("tpu") == "fused"
-        assert kl.resolve_inference_method("cpu", rows=1) == "compact"
+        assert kl.resolve_inference_method("cpu") == "compact"
 
     def test_threshold_env_override(self, monkeypatch):
-        monkeypatch.setenv("KAN_SAS_SPARSE_MAX_ROWS", "64")
-        assert kl.resolve_inference_method("tpu", rows=64) == "sparse"
+        monkeypatch.setenv("KAN_SAS_INFERENCE_METHOD", "compact")
+        assert kl.resolve_inference_method("tpu") == "compact"
         monkeypatch.setenv("KAN_SAS_INFERENCE_METHOD", "fused")
-        assert kl.resolve_inference_method("tpu", rows=1) == "fused"
+        assert kl.resolve_inference_method("cpu") == "fused"
+        monkeypatch.setenv("KAN_SAS_INFERENCE_METHOD", "sparse")
+        assert kl.resolve_inference_method("cpu") == "sparse"
+        with pytest.raises(ValueError, match="not compiled for the TPU"):
+            kl.resolve_inference_method("tpu")
 
     def test_auto_uses_row_count(self, monkeypatch):
-        """kan_layer_apply('auto') resolves per flattened row count: decode
-        row counts pick the sparse kernel when the backend heuristic says
-        TPU (forced here via the env override)."""
+        """kan_layer_apply('auto') resolves per backend; the env override
+        runs the sparse kernel in interpret mode off-TPU."""
         g, params = _layer(5, 3, 8, 6)
         x = jnp.zeros((4, 8), jnp.float32)
         y = kl.kan_layer_apply(params, x, g, "auto")   # cpu -> compact

@@ -38,7 +38,7 @@ from repro.serve.kv_pool import BlockPool, blocks_for, worst_case_blocks
 from repro.serve.scheduler import ContinuousScheduler
 from repro.serve.speculative import DraftModel
 
-from conftest import run_jax_subprocess
+from conftest import assert_logits_match, assert_trees_match, run_jax_subprocess
 
 MAX_NEW = 6
 
@@ -285,9 +285,11 @@ def test_complete_spec_window_variable_emissions():
 
 @pytest.mark.parametrize("quant_kv", [False, True])
 def test_verify_window_logits_match_sequential_decode(quant_kv):
-    """THE batch-axis invariance the whole acceptance rule stands on:
-    scoring W window positions in one fused forward must produce bitwise
-    the same logits as W sequential decode_step calls."""
+    """The batch-axis invariance the acceptance rule stands on: scoring W
+    window positions in one fused forward gives the logits (and caches) of
+    W sequential decode_step calls.  They are different programs, so the
+    match is to the fp32 reorder tolerance, with equal greedy tokens
+    wherever the margin decides them."""
     arch, params = arch_params()
     model = arch.model
     if quant_kv:
@@ -313,11 +315,10 @@ def test_verify_window_logits_match_sequential_decode(quant_kv):
         params, model, window, ver_caches, pos, jnp.float32)
     assert ver_logits.shape == (2, W, model.vocab)
     for j in range(W):
-        np.testing.assert_array_equal(
-            np.asarray(seq_logits[j]), np.asarray(ver_logits[:, j]),
+        assert_logits_match(
+            seq_logits[j], ver_logits[:, j],
             err_msg=f"window position {j} diverged (quant_kv={quant_kv})")
-    for a, b in zip(jax.tree.leaves(seq_caches), jax.tree.leaves(ver_caches)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert_trees_match(seq_caches, ver_caches)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ from repro import configs
 from repro.checkpoint import store
 from repro.data import pipeline as dp
 from repro.dist import compression, sharding as SH
+from repro.launch.mesh import auto_mesh
 from repro.models import lm
 from repro.models.layers import Axes
 from repro.optim import adamw
@@ -149,12 +150,12 @@ class TestServeEngine:
 
 class TestShardingRules:
     def _mesh(self):
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return auto_mesh((1, 1), ("data", "model"))
 
     def test_divisibility_fallback(self):
         import jax.sharding as js
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         # heads=40 with model=1 divides trivially; simulate size via fake mesh
         spec = SH.spec_for(Axes(("embed", "heads", "head_dim")), (64, 40, 16), mesh)
         assert isinstance(spec, js.PartitionSpec)
@@ -163,14 +164,14 @@ class TestShardingRules:
         """The real divisibility logic, on shapes that don't divide."""
         # fabricate a mesh dict-alike via the actual API with 1 device but
         # pretend sizes using the internal helpers
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         spec = SH.spec_for(Axes(("vocab", "embed")), (151936, 1024), mesh)
         assert spec[0] == "model"  # vocab takes the model axis
 
     def test_zero_spec_adds_data(self):
         import jax.sharding as js
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         base = js.PartitionSpec(None, "model")
         z = SH.zero_spec(base, (64, 32), mesh)
         assert z[0] == "data"
