@@ -257,7 +257,8 @@ def _kv_quantize(k: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q.astype(jnp.int8), scale
 
 
-def _write_cache(cache: dict, name: str, val: jax.Array, slot: jax.Array, quant: bool):
+def _write_cache(cache: dict, name: str, val: jax.Array, slot: jax.Array,
+                 quant: bool, layer=None):
     """Write one token's K or V into the (ring) cache at ``slot``.
 
     Two regimes (EXPERIMENTS.md §Perf iterations 1 & 4):
@@ -272,28 +273,37 @@ def _write_cache(cache: dict, name: str, val: jax.Array, slot: jax.Array, quant:
 
     Both replace the original masked-arithmetic update, which materialised
     two cache-sized temporaries (+13 GB/device on qwen1.5-32b decode_32k).
+    With ``layer`` the leaves are the unit's stacked caches ``(R, B, S,
+    ...)`` and the token lands at ``[layer, b, slot]``, in place.
     """
-    sync = slot.ndim == 0
     if quant:
         qv, sc = _kv_quantize(val)                            # (B,1,KV,D)
-        if sync:
-            cache[name] = jax.lax.dynamic_update_slice_in_dim(
-                cache[name], qv, slot, axis=1)
-            cache[name + "_scale"] = jax.lax.dynamic_update_slice_in_dim(
-                cache[name + "_scale"], sc, slot, axis=1)
-        else:
-            b_idx = jnp.arange(val.shape[0])
-            cache[name] = cache[name].at[b_idx, slot].set(qv[:, 0])
-            cache[name + "_scale"] = cache[name + "_scale"].at[b_idx, slot].set(sc[:, 0])
+        cache[name] = _put_token(cache[name], qv, slot, layer)
+        cache[name + "_scale"] = _put_token(
+            cache[name + "_scale"], sc, slot, layer)
     else:
-        v = val.astype(cache[name].dtype)
-        if sync:
-            cache[name] = jax.lax.dynamic_update_slice_in_dim(
-                cache[name], v, slot, axis=1)
-        else:
-            b_idx = jnp.arange(val.shape[0])
-            cache[name] = cache[name].at[b_idx, slot].set(v[:, 0])
+        cache[name] = _put_token(
+            cache[name], val.astype(cache[name].dtype), slot, layer)
     return cache
+
+
+def _put_token(a: jax.Array, u: jax.Array, slot: jax.Array, layer) -> jax.Array:
+    """``a[(layer,) b, slot[b]] = u[b, 0]`` for one-token values ``u (B, 1,
+    ...)``: a dynamic update slice for a scalar ``slot``, a scatter for a
+    per-row one."""
+    lead = () if layer is None else (layer,)
+    if slot.ndim == 0:
+        return jax.lax.dynamic_update_slice(
+            a, u.reshape((1,) * len(lead) + u.shape),
+            lead + (0, slot) + (0,) * (u.ndim - 2))
+    return a.at[lead + (jnp.arange(u.shape[0]), slot)].set(u[:, 0])
+
+
+def _layer_view(cache: dict, layer) -> dict:
+    """Layer ``layer`` of a stacked cache tree: the ``(B, S, ...)`` leaves
+    one block attends over."""
+    return {k: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for k, a in cache.items()}
 
 
 # Logical axes of each GQA cache-dict leaf, for with_sharding_constraint
@@ -317,14 +327,17 @@ POOL_CACHE_AXES = {
 }
 
 
-def _constrain_cache(cache: dict, shard, paged: bool) -> dict:
+def _constrain_cache(cache: dict, shard, paged: bool,
+                     stacked: bool = False) -> dict:
     """Pin freshly written cache leaves to their logical-axes shardings so
     GSPMD keeps KV distributed through decode updates (no-op without a
-    ``shard`` ctx, and bit-identical under a 1-device mesh)."""
+    ``shard`` ctx, and bit-identical under a 1-device mesh).  ``stacked``
+    leaves carry the unit's leading 'layers' axis."""
     if shard is None:
         return cache
     table = POOL_CACHE_AXES if paged else DENSE_CACHE_AXES
-    return {k: shard.constrain(v, table[k]) for k, v in cache.items()}
+    lead = ("layers",) if stacked else ()
+    return {k: shard.constrain(v, lead + table[k]) for k, v in cache.items()}
 
 
 def _read_cache(cache: dict, name: str, quant: bool, dtype):
@@ -342,6 +355,7 @@ def attn_decode_step(
     cache: dict,                    # {"k","v"[, "k_scale","v_scale"]}
     pos: jax.Array,                 # (B,) current absolute position
     shard=None,                     # optional ShardingCtx (mesh serving)
+    layer=None,                     # index into stacked (R, B, S, ...) leaves
 ) -> tuple[jax.Array, dict]:
     """One-token decode against a pre-filled KV cache.
 
@@ -353,17 +367,24 @@ def attn_decode_step(
     writes) or per-batch ``(B,)`` (ragged/continuous batching).  With a
     ``shard`` ctx the updated cache leaves are constraint-pinned to their
     logical-axes shardings (kv_heads on ``model``, batch on ``data``).
+
+    With ``layer`` the leaves are the unit's stacked caches, carried
+    through the decode layer scan: the token is written at ``[layer, b,
+    slot]`` in place and layer ``layer`` is read back for the attention,
+    so no cache-sized slice or stacked output is built per layer.
     """
     B = x.shape[0]
-    S = cache["k"].shape[1]
+    S = cache["k"].shape[1 if layer is None else 2]
     pos_b = jnp.broadcast_to(pos, (B,))          # per-batch view for masks
     q, k, v = _project_qkv(params, cfg, x, pos_b[:, None])
     slot = pos % S if cfg.window else pos
     cache = dict(cache)
-    cache = _write_cache(cache, "k", k, slot, cfg.kv_quant)
-    cache = _write_cache(cache, "v", v, slot, cfg.kv_quant)
-    cache = _constrain_cache(cache, shard, paged=False)
-    y = _cache_attend(params, cfg, x, cache, q, pos_b)
+    cache = _write_cache(cache, "k", k, slot, cfg.kv_quant, layer)
+    cache = _write_cache(cache, "v", v, slot, cfg.kv_quant, layer)
+    cache = _constrain_cache(cache, shard, paged=False,
+                             stacked=layer is not None)
+    view = cache if layer is None else _layer_view(cache, layer)
+    y = _cache_attend(params, cfg, x, view, q, pos_b)
     return y, cache
 
 
@@ -531,19 +552,20 @@ def paged_route(
 
 def _paged_write_token(
     cache: dict, name: str, val: jax.Array, table: jax.Array,
-    pos_b: jax.Array, quant: bool,
+    pos_b: jax.Array, quant: bool, layer=None,
 ) -> dict:
     """Write one decode token's K or V into its pool block: the T=1 case of
     :func:`paged_write_span` (per-row start ``pos_b``, every position
     real).  ``lengths = pos_b + 1`` makes the span's pad mask vacuous while
     keeping its out-of-coverage sentinel redirect — one definition of the
     write routing the bit-identity contract depends on."""
-    return paged_write_span(cache, name, val, table, pos_b, pos_b + 1, quant)
+    return paged_write_span(cache, name, val, table, pos_b, pos_b + 1, quant,
+                            layer)
 
 
 def paged_write_span(
     cache: dict, name: str, val: jax.Array, table: jax.Array,
-    start: jax.Array, lengths: jax.Array, quant: bool,
+    start: jax.Array, lengths: jax.Array, quant: bool, layer=None,
 ) -> dict:
     """Scatter a span of K or V into pool blocks.
 
@@ -556,21 +578,23 @@ def paged_write_span(
     The same redirect absorbs positions past the table span: fixed-shape
     chunks overrun finished rows, and retired slots' table rows are reset
     to sentinel — duplicate sentinel writes are unordered but the sentinel
-    is never attendable.
+    is never attendable.  With ``layer`` the pools are the unit's stacked
+    pools ``(R, n_blocks, bs, ...)`` and the span lands in layer ``layer``.
     """
     pool = cache[name]
     B, T = val.shape[:2]
-    bs = pool.shape[1]
+    bs = pool.shape[1 if layer is None else 2]
     starts = jnp.reshape(jnp.asarray(start), (-1, 1))      # scalar or (B,)
     positions = jnp.broadcast_to(starts + jnp.arange(T)[None, :], (B, T))
     phys, off = paged_route(table, positions, bs,
                             valid=positions < lengths[:, None])
+    at = (phys, off) if layer is None else (layer, phys, off)
     if quant:
         qv, sc = _kv_quantize(val)
-        cache[name] = pool.at[phys, off].set(qv)
-        cache[name + "_scale"] = cache[name + "_scale"].at[phys, off].set(sc)
+        cache[name] = pool.at[at].set(qv)
+        cache[name + "_scale"] = cache[name + "_scale"].at[at].set(sc)
     else:
-        cache[name] = pool.at[phys, off].set(val.astype(pool.dtype))
+        cache[name] = pool.at[at].set(val.astype(pool.dtype))
     return cache
 
 
@@ -582,11 +606,14 @@ def attn_decode_step_paged(
     table: jax.Array,               # (B, n_logical) int32 block table
     pos: jax.Array,                 # (B,) absolute positions
     shard=None,                     # optional ShardingCtx (mesh serving)
+    layer=None,                     # index into stacked (R, n_blocks, ...) pools
 ) -> tuple[jax.Array, dict]:
     """One-token decode against the paged pool: identical QKV math, writes
     routed through the block table, then :func:`_cache_attend` on the
     gathered logical view — bit-identical to :func:`attn_decode_step` on
-    the dense contiguous cache (tested in ``tests/test_kv_pool.py``)."""
+    the dense contiguous cache (tested in ``tests/test_kv_pool.py``).
+    With ``layer`` the pools are the unit's stacked pools, written in place
+    at ``layer`` and gathered from that layer alone."""
     assert cfg.window is None and cfg.kv_lora_rank is None, (
         "paged KV supports full-attention GQA layers only"
     )
@@ -594,10 +621,11 @@ def attn_decode_step_paged(
     pos_b = jnp.broadcast_to(pos, (B,))
     q, k, v = _project_qkv(params, cfg, x, pos_b[:, None])
     cache = dict(cache)
-    cache = _paged_write_token(cache, "k", k, table, pos_b, cfg.kv_quant)
-    cache = _paged_write_token(cache, "v", v, table, pos_b, cfg.kv_quant)
-    cache = _constrain_cache(cache, shard, paged=True)
-    y = _cache_attend(params, cfg, x, paged_view(cache, table, shard), q, pos_b)
+    cache = _paged_write_token(cache, "k", k, table, pos_b, cfg.kv_quant, layer)
+    cache = _paged_write_token(cache, "v", v, table, pos_b, cfg.kv_quant, layer)
+    cache = _constrain_cache(cache, shard, paged=True, stacked=layer is not None)
+    pools = cache if layer is None else _layer_view(cache, layer)
+    y = _cache_attend(params, cfg, x, paged_view(pools, table, shard), q, pos_b)
     return y, cache
 
 

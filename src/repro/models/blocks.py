@@ -425,7 +425,27 @@ def block_decode_step(
     pos: jax.Array,             # (B,)
     table: jax.Array | None = None,   # (B, n_logical): paged block table
     shard=None,                 # optional ShardingCtx (mesh serving)
+    layer=None,                 # index into the unit's stacked leaves
 ) -> tuple[jax.Array, dict]:
+    """One decode step of one block.
+
+    With ``layer`` set, ``cache`` holds the unit's stacked leaves (a
+    leading layers axis), carried through the decode layer scan, and this
+    block is layer ``layer`` of them.  GQA attention (full or windowed,
+    dense or paged, bf16 or int8) writes its token into the stacked K/V in
+    place and reads its own layer back; blocks whose state is per sequence
+    (MLA's latent, mamba2, xLSTM) slice their layer, step it and put it
+    back."""
+    gqa = (blk.kind in ("attn_mlp", "attn_moe", "attn_kan")
+           and blk.attn.kv_lora_rank is None)
+    if layer is not None and not gqa:
+        one = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+            cache)
+        x, one = block_decode_step(params, blk, x, one, pos, table, shard)
+        return x, jax.tree.map(
+            lambda a, o: jax.lax.dynamic_update_index_in_dim(a, o, layer, 0),
+            cache, one)
     h = L.rmsnorm(params["ln1"], x)
     if table is not None and not block_supports_paging(blk):
         raise NotImplementedError(f"paged decode: unsupported kind {blk.kind!r}")
@@ -433,7 +453,8 @@ def block_decode_step(
         c = blk.attn
         if table is not None:
             y, cache = A.attn_decode_step_paged(
-                params["attn"], c, h, cache, table, pos, shard=shard
+                params["attn"], c, h, cache, table, pos, shard=shard,
+                layer=layer,
             )
         elif c.kv_lora_rank is not None:
             # shard threads into the step itself (the latent is pinned at
@@ -444,7 +465,7 @@ def block_decode_step(
             cache = {"ckv": ckv}
         else:
             y, cache = A.attn_decode_step(
-                params["attn"], c, h, cache, pos, shard=shard
+                params["attn"], c, h, cache, pos, shard=shard, layer=layer
             )
         x = x + y
         h2 = L.rmsnorm(params["ln2"], x)

@@ -773,28 +773,30 @@ def decode_step(
             ncs.append(c2)
         new_caches["prologue"] = ncs
 
+    # The unit's stacked caches are the layer scan's carry: each block
+    # writes its token at [layer, ...] in place and reads its own layer,
+    # so no per-layer cache slice goes in and no stacked copy comes out.
     def unit_body(carry, xs):
-        h_c = carry
-        rep_params, rep_caches = xs
-        new_rep = []
+        h_c, unit_c = carry
+        layer, rep_params = xs
+        unit_c = list(unit_c)
         for i, blk in enumerate(cfg.unit):
             p = shared[blk.shared_id] if blk.shared_id is not None else rep_params[i]
-            h_c, c2 = B.block_decode_step(
-                p, blk, h_c, rep_caches[i], pos, table, shard
+            h_c, unit_c[i] = B.block_decode_step(
+                p, blk, h_c, unit_c[i], pos, table, shard, layer
             )
-            new_rep.append(c2)
-        return h_c, new_rep
+        return (h_c, unit_c), None
 
+    new_unit = list(caches["unit"])
     if cfg.scan_layers:
-        h, new_unit = jax.lax.scan(unit_body, h, (params["unit"], caches["unit"]))
+        (h, new_unit), _ = jax.lax.scan(
+            unit_body, (h, new_unit),
+            (jnp.arange(cfg.n_repeats), params["unit"]),
+        )
     else:
-        reps = []
         for r in range(cfg.n_repeats):
             rep_p = jax.tree.map(lambda a: a[r], params["unit"])
-            rep_c = jax.tree.map(lambda a: a[r], caches["unit"])
-            h, nc = unit_body(h, (rep_p, rep_c))
-            reps.append(nc)
-        new_unit = jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
+            (h, new_unit), _ = unit_body((h, new_unit), (r, rep_p))
     new_caches["unit"] = new_unit
 
     if cfg.epilogue:

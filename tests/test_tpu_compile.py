@@ -14,7 +14,10 @@ Pallas kernel on the served TPU path at kanformer-100m's widths:
   layers;
 * one full-model decode step on the 2x2 (data, model) mesh of all four
   chips, where the kernels run under ``shard_map``, and the paged gather
-  there, with the bytes of pool it all-gathers.
+  there, with the bytes of pool it all-gathers;
+* the engine's decode chunk on one chip at 16 slots of 3120 tokens, whose
+  step and layer loops write one token per layer into the carried view
+  and build no copy of it.
 
 The sparse N:M kernel is not on the TPU path (``resolve_inference_method``
 never returns it there), so it is not compiled here.  The topology is
@@ -202,3 +205,58 @@ def test_mesh_paged_gather_all_gathers_the_pool(topo, monkeypatch, pool_blocks):
         (MODEL.n_repeats, pool_blocks, bs, kv_heads_per_device, POOL[3])) * 2
     expect = whole_pools if pool_blocks % 2 == 0 else 0
     assert _all_gather_bytes(txt) == expect
+
+
+def _loop_ops_of_shapes(hlo: str, shapes: set) -> list:
+    """``(op, fused root op)`` of every unfused instruction outside the
+    entry computation (the bodies of the step and layer loops) whose result
+    has one of ``shapes``; parameters, tuples and bitcasts move nothing."""
+    import re
+
+    comps = re.split(r"\n(?=(?:ENTRY )?%\S+ \()", hlo)
+    roots = {}
+    for c in comps:
+        m = re.search(r"ROOT %\S+ = \S+ ([\w-]+)\(", c)
+        roots[c.split(" (")[0].lstrip("%")] = m.group(1) if m else None
+    found = []
+    for c in comps:
+        name = c.split(" (")[0]
+        if name.startswith("ENTRY") or "fused_computation" in name or name.endswith("_fusion"):
+            continue
+        for ln in c.splitlines()[1:]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(", ln)
+            if not m or m.group(1) not in shapes or m.group(2) in (
+                    "get-tuple-element", "parameter", "tuple", "bitcast"):
+                continue
+            calls = re.search(r"calls=%([^,\s]+)", ln)
+            found.append((m.group(2), roots.get(calls.group(1)) if calls else None))
+    return found
+
+
+def test_decode_chunk_writes_one_token_per_layer(one_chip, monkeypatch):
+    """The engine's decode chunk (8 steps) at the size of the served prefill
+    workload: 16 slots, 3120 positions, kanformer-100m's 8 layers of 8 kv
+    heads of 64.  The stacked caches are the layer loop's carry, so inside
+    the loops the only instruction that produces the view, or one layer of
+    it, is the in-place scatter of each layer's token; the layer the
+    attention reads is a dynamic slice fused into its reductions."""
+    from repro.models import lm
+    from repro.serve.engine import Engine, ServeConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_seq = 16, 3120
+    sds = lambda a: _sds(a.shape, a.dtype, one_chip)  # noqa: E731
+    params = jax.tree.map(sds, lm.abstract_params(MODEL))
+    caches = jax.tree.map(
+        sds, lm.abstract_caches(MODEL, slots, max_seq, jnp.bfloat16))
+    eng = Engine(params, MODEL, ServeConfig(max_seq=max_seq,
+                                            compute_dtype="bfloat16"))
+    i32 = lambda *s: _sds(s, jnp.int32, one_chip)  # noqa: E731
+    hlo = eng._decode_scan.lower(
+        8, params, i32(slots, 1), caches, i32(slots),
+        _sds((slots, 2), jnp.uint32, one_chip),
+        _sds((slots,), jnp.bool_, one_chip), i32(), i32(),
+    ).compile().as_text()
+    view = caches["unit"][0]["k"].shape
+    shapes = {",".join(map(str, view)), ",".join(map(str, view[1:]))}
+    assert _loop_ops_of_shapes(hlo, shapes) == [("fusion", "scatter")] * 2
