@@ -54,9 +54,12 @@ def logits(w, cfg, tokens, rows, lowp=False):
                             lambda h, lw, lowp: ffn(h, lw, cfg, lowp), lowp)
 
 
-def ffn_flops(cfg):
-    """Per token: both KAN layers, ``M = G + P`` basis planes and the base
-    term each, ``2·K·(M + 1)·N``."""
+def layer_flops(cfg):
+    """Operations of all layers, ``(per position, per attended context
+    position)``: attention (``refops.gqa_flops``) and both KAN layers,
+    ``M = G + P`` basis planes and the base term each, ``2·K·(M + 1)·N``."""
     d, ff = cfg["hidden_size"], cfg["kan_intermediate_size"]
     m1 = cfg["kan_grid_size"] + cfg["kan_spline_order"] + 1
-    return 2 * d * m1 * ff + 2 * ff * m1 * d
+    pos, ctx = R.gqa_flops(cfg)
+    L = cfg["num_hidden_layers"]
+    return L * (pos + 2 * d * m1 * ff + 2 * ff * m1 * d), L * ctx

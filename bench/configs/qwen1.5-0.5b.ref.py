@@ -45,6 +45,10 @@ def logits(w, cfg, tokens, rows, lowp=False):
     return R.decoder_logits(w, cfg, tokens, rows, ffn, lowp)
 
 
-def ffn_flops(cfg):
-    """Per token: gate, up and down projections."""
-    return 3 * 2 * cfg["hidden_size"] * cfg["intermediate_size"]
+def layer_flops(cfg):
+    """Operations of all layers, ``(per position, per attended context
+    position)``: attention (``refops.gqa_flops``) and the FFN's gate, up
+    and down projections."""
+    pos, ctx = R.gqa_flops(cfg)
+    L = cfg["num_hidden_layers"]
+    return L * (pos + 3 * 2 * cfg["hidden_size"] * cfg["intermediate_size"]), L * ctx

@@ -1,11 +1,12 @@
 #!/usr/bin/env python3
 """Readings that the limit of a cell's correctness check is set from.
 
-    python3 bench/control.py --workload <cell> --seconds <s> --seeds <n> [<n> ...]
+    python3 bench/control.py --workload <cell> --rounds <r> --seeds <n> [<n> ...]
                              [--fault token|kan_plane|kan_zero]
 
 For each seed, in one process: the cell's weights and traffic from the
-seed, a short window of the cell's own rounds through the engine, and the
+seed, ``rounds`` of the cell's own rounds through the engine (a fixed
+number, so that the sample does not depend on how fast they run), and the
 same sample of finished requests that a run checks.  On that sample it
 reads the program's widest gap (what a run compares with its limit) and
 the control's: the plain reference computed in float8, put in the
@@ -28,7 +29,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from harness import check, faults, manifest, runner, traffic as traffic_mod  # noqa: E402
 
 
-def readings(cell, seeds, seconds: float, log=print):
+def readings(cell, seeds, rounds: int, log=print):
     """Yield ``{"seed", "program_gap", "program_correct", "control_gap",
     "control_correct", ...}`` per seed.  One engine serves every seed; its
     weights are replaced between seeds."""
@@ -46,15 +47,15 @@ def readings(cell, seeds, seconds: float, log=print):
             runner.warm_up(eng, mix, traffic, seed)
         else:
             eng.params = params
-        rounds, window_s = runner.window(eng, mix, traffic, seconds, seed)
-        failed = runner.failures(rounds, vocab)
-        samples = runner.check_sample(runner.served(rounds, mix), mix, seed)
+        done = [runner.serve_round(eng, mix, traffic.next_round(), seed)
+                for _ in range(rounds)]
+        failed = runner.failures(done, vocab)
+        samples = runner.check_sample(runner.served(done, mix), mix, seed)
         ref.load(runner.weight_key(seed))
         program_gap = check.widest_gap(ref, samples)
         control_gap = check.control_gap(ref, samples)
-        row = {"seed": seed, "rounds": len(rounds), "window_s": window_s,
-               "failed": failed, "sample_requests": len(samples),
-               "sample_tokens": sum(len(o) for _, o in samples),
+        row = {"seed": seed, "rounds": rounds, "failed": failed,
+               "sample_requests": len(samples), "sample_tokens": sum(len(o) for _, o in samples),
                "program_gap": program_gap,
                "program_correct": check.verdict(failed, program_gap, cell.limits)[0],
                "control_gap": control_gap,
@@ -68,7 +69,7 @@ def readings(cell, seeds, seconds: float, log=print):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--rounds", type=int, required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--fault", choices=sorted(faults.FAULTS))
     args = ap.parse_args(argv)
@@ -84,7 +85,7 @@ def main(argv=None) -> int:
         print("[control] needs a TPU", file=sys.stderr)
         return 3
     with faults.planted(args.fault):
-        for _ in readings(cell, args.seeds, args.seconds,
+        for _ in readings(cell, args.seeds, args.rounds,
                           log=lambda s: print(s, flush=True)):
             pass
     return 0

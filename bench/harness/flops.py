@@ -1,11 +1,12 @@
 """Operations and bytes the work needs, from the configuration's shapes.
 
-``model_flops`` counts a decoder's forward pass per token position: the
-attention projections, attention itself over the position's context, the
-FFN (from the configuration's reference, ``ffn_flops``), and the output
-head once per generated token.  It is the yardstick of ``mfu``; it counts
-what the work needs, not what an implementation happens to compute, so
-padding and recomputed prefixes are not in it.
+``model_flops`` counts a decoder's forward pass over a request's token
+positions: its layers, as the configuration's reference counts them
+(``layer_flops`` in ``<config>.ref.py``: per position and per attended
+context position, summed over the layers), and the output head once per
+generated token.  It is the yardstick of ``mfu``; it counts what the work
+needs, not what an implementation happens to compute, so padding and
+recomputed prefixes are not in it.
 """
 
 from __future__ import annotations
@@ -35,10 +36,9 @@ def request_positions(prompt_len: int, cached: int, n_out: int) -> tuple[int, in
     return n, (first + 1 + last + 1) * n // 2
 
 
-def model_flops(cfg: dict, ffn_flops, positions: int, context_sum: int,
+def model_flops(cfg: dict, ref, positions: int, context_sum: int,
                 head_tokens: int) -> float:
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    H, KV, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    per_pos = L * (2 * d * (H + 2 * KV) * hd + 2 * H * hd * d + ffn_flops(cfg))
-    attn = L * 4 * H * hd * context_sum
-    return float(positions * per_pos + attn + head_tokens * 2 * d * cfg["vocab_size"])
+    """``ref``: the configuration's reference module."""
+    per_pos, per_ctx = ref.layer_flops(cfg)
+    head = 2 * cfg["hidden_size"] * cfg["vocab_size"]
+    return float(positions * per_pos + per_ctx * context_sum + head_tokens * head)

@@ -111,6 +111,16 @@ def decoder_logits(w: dict, cfg: dict, tokens: jax.Array, rows: jax.Array,
     return mm("rd,vd->rv", h, w["embed"], lowp)
 
 
+def gqa_flops(cfg: dict) -> tuple[int, int]:
+    """One attention layer of :func:`decoder_logits`: ``(operations per
+    position, operations per attended context position)``.  Per position
+    the query, key, value and output projections; per context position
+    ``q·k`` and ``p·v`` of every query head."""
+    d, hd = cfg["hidden_size"], cfg["head_dim"]
+    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    return 2 * d * (H + 2 * KV) * hd + 2 * H * hd * d, 4 * H * hd
+
+
 def normal(key: jax.Array, shapes: dict) -> dict:
     """``{name: std * N(0, 1)}`` for ``shapes = {name: (shape, std)}``,
     one key per name."""

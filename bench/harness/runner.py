@@ -47,7 +47,7 @@ class RunData:
             cached = r.round.cached_prompt_tokens(m.block_size, m.prefix_len)
             for p, n, c in zip(r.round.prompts, r.round.budgets, cached):
                 pos, ctx = flops.request_positions(len(p), c, n)
-                total += flops.model_flops(self.cell.cfg, self.cell.ref.ffn_flops,
+                total += flops.model_flops(self.cell.cfg, self.cell.ref,
                                            pos, ctx, n)
         return total
 

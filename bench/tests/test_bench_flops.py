@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from harness import flops, manifest
+from harness import flops, manifest, runner, traffic as T
 
 CONFIGS = os.path.join(manifest.BENCH, "configs")
 
@@ -38,7 +38,26 @@ def test_model_flops(name, per_layer, head, attn_per_ctx):
     L = cfg["num_hidden_layers"]
     pos, ctx = flops.request_positions(100, 0, 10)
     want = pos * L * per_layer + L * attn_per_ctx * ctx + 10 * head
-    assert flops.model_flops(cfg, ref.ffn_flops, pos, ctx, 10) == want
+    assert flops.model_flops(cfg, ref, pos, ctx, 10) == want
+
+
+@pytest.mark.parametrize("name, seed, want", [
+    # one round of each cell at two seeds, to the operation: what mfu
+    # divides by: for these two configurations, one GQA layer and the
+    # FFN times the layers, and the head
+    ("kanformer-100m.prefill", 2**31 + 7, 33_246_628_167_680),
+    ("kanformer-100m.prefill", 5, 33_246_322_819_072),
+    ("kanformer-100m.decode", 2**31 + 7, 7_521_871_101_952),
+    ("kanformer-100m.decode", 5, 7_522_585_346_048),
+    ("qwen1.5-0.5b.decode", 2**31 + 7, 66_719_231_770_624),
+    ("qwen1.5-0.5b.decode", 5, 66_718_019_584_000),
+])
+def test_model_flops_of_a_cells_round(name, seed, want):
+    cell = manifest.resolve(manifest.load(), name)
+    mix = T.load_mix(manifest.traffic_path(cell.traffic_name), cell.spec)
+    rnd = T.Traffic(mix, cell.cfg["vocab_size"], seed).next_round()
+    data = runner.RunData(cell, mix, [runner.RoundResult(rnd, [], {}, [])], 1.0, None, None)
+    assert data.model_flops() == float(want)
 
 
 def test_kanformer_fused_kernel_counts():

@@ -22,8 +22,11 @@ TRAFFIC = {
     "decode": {"prompt": {"dist": "lognormal", "median": 12, "sigma": 0.5, "min": 4, "max": 32},
                "output": {"dist": "lognormal", "median": 12, "sigma": 0.5, "min": 4, "max": 24}},
 }
-# At these widths, on seeds 3, 2**31 + 5 and 11-14, the bf16 program's
-# widest gap read 0-0.034 and the float8 control's 0.107-0.504.
+# At these widths, with a check of 96 tokens from 8 requests, on seeds 3,
+# 2**31 + 5 and 11-20, after 1 round and after 4, both cells: the bf16
+# program's widest gap read 0-0.0435 and the float8 control's 0.115-0.593.
+# A check of 32 tokens from 4 requests was too small: the control read
+# 0.0 on two seeds after 4 rounds, 0.057 on one after 1.
 LIMIT = 0.07
 
 
@@ -62,7 +65,7 @@ def small_cell(name):
     cell.spec = {
         "serve": {"round_requests": 8, "slots": 4, "chunk_steps": 8,
                   "block_size": 16, "prompt_pad_multiple": 32},
-        "check": {"tokens": 32, "requests": 4},
+        "check": {"tokens": 96, "requests": 8},
         "limits": {"logit_gap": LIMIT}}
     return cell
 
@@ -114,9 +117,13 @@ def test_short_output_fails(monkeypatch):
     assert not res["correct"] and res["failed"] > 0
 
 
+@pytest.mark.parametrize("rounds", [1, 4])
 @pytest.mark.parametrize("name", ["kanformer-100m.prefill", "qwen1.5-0.5b.decode"])
-def test_control_reads_above_the_program(name):
-    rows = list(control.readings(small_cell(name), [3, 2**31 + 5], 0.5, log=lambda s: None))
+def test_control_reads_above_the_program(name, rounds):
+    """A fixed number of rounds per seed, so that the sample is the same on
+    every run whatever the machine's load."""
+    rows = list(control.readings(small_cell(name), [3, 2**31 + 5], rounds,
+                                 log=lambda s: None))
     for r in rows:
         assert r["failed"] == 0
         assert r["program_gap"] <= LIMIT, r

@@ -69,7 +69,7 @@ def test_stratified_quantiles():
 
 def test_max_seq_as_the_launcher_sizes_it():
     assert mix("prefill").max_seq() == 3120
-    assert mix("decode").max_seq() == 784
+    assert mix("decode").max_seq() == 400
 
 
 def test_warmup_reaches_every_prefill_shape():
@@ -86,7 +86,7 @@ def test_warmup_reaches_every_prefill_shape():
         pad = -(-(len(p) - c) // m.prompt_pad_multiple) * m.prompt_pad_multiple
         assert min(pad, m.max_seq() - c) in buckets[c]
     d = mix("decode")
-    assert {s: sorted(b) for s, b in T.Traffic(d, 32000, 3).prefill_buckets(d.max_seq()).items()} == {0: [512]}
+    assert {s: sorted(b) for s, b in T.Traffic(d, 32000, 3).prefill_buckets(d.max_seq()).items()} == {0: [128]}
 
 
 def test_a_mix_holds_only_the_mix(tmp_path):
